@@ -130,7 +130,7 @@ class PagePool:
 
     This is the MTT analogue (DESIGN.md §3): the pool owns *allocation*
     metadata — which pages are free, which sequence maps to which pages —
-    while the page tensors themselves (``[n_pages, page_size, KV, hd]``
+    while the page tensors themselves (``[n_pages, KV, page_size, hd]``
     per layer) live in the serving state. ``ensure_capacity`` implements
     alloc-on-append: the engine calls it with the token count *about to be
     written* and pages are claimed exactly at page-boundary crossings, so

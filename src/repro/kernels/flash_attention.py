@@ -23,12 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces (interpret mode works without them)
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -114,8 +109,9 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     def kv_map(bh, i, j):
         return ((bh // H) * KV + (bh % H) // G, j, 0)
 
-    scratch = [_SCRATCH((block_q,)), _SCRATCH((block_q,)),
-               _SCRATCH((block_q, hd))]
+    scratch = [pltpu.VMEM((block_q,), jnp.float32),
+               pltpu.VMEM((block_q,), jnp.float32),
+               pltpu.VMEM((block_q, hd), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_fa_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, seq_len=S, window=window, n_k=n_k),

@@ -19,12 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _GridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    _GridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _dispatch_kernel(eids_ref, pos_ref, tok_ref, init_ref, out_ref):
@@ -47,7 +42,7 @@ def moe_dispatch(tokens, expert_ids, positions, n_experts: int,
     def out_map(t, eids_s, pos_s):
         return (eids_s[t], pos_s[t], 0)
 
-    grid_spec = _GridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T,),
         in_specs=[

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode (semantics
-validated against kernels/ref.py); on TPU the same calls lower to Mosaic.
-``interpret=None`` auto-detects.
+Off the TPU the kernels execute in interpret mode (semantics validated
+against kernels/ref.py); on TPU they lower to Mosaic, whose tiling rules
+interpret mode does not check. tests/test_chip_compile.py compiles the
+paged decode kernel for a described v5e chip; the other kernels have not
+been compiled for the chip. ``interpret=None`` auto-detects.
 """
 from __future__ import annotations
 

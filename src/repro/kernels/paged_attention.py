@@ -10,7 +10,11 @@ Two backends behind one entry point:
 
 - ``backend="pallas"`` — the TPU kernel below (interpret mode on CPU).
   Grid (B, KV, MP), last dim sequential; q: [B, H, hd]; k_pages/v_pages:
-  [NP, page, KV, hd]; page_table: [B, MP] int32; lengths: [B] int32.
+  [NP, KV, page, hd]; page_table: [B, MP] int32; lengths: [B] int32.
+  The pools are KV-head-major so one grid step's block is a whole
+  ``(page, hd)`` tile: Mosaic requires a block's last two dims to be
+  (8, 128)-divisible or full, which a size-1 slice of a token-major
+  ``[NP, page, KV, hd]`` pool's KV axis is not.
 - ``backend="jnp"`` — a dense gather (``k_pages[page_table]``) feeding
   plain softmax attention; fast under jit on CPU, and the shape contract
   oracle for the kernel (see kernels/ref.py).
@@ -28,14 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-    _GridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    _SCRATCH = None
-    _GridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -62,19 +59,19 @@ def _pd_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(in_range)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)         # [G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # [page, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)         # [page, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [G, page]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        pr = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                         # [G, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        pr = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + pr.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + pr.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             pr, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -83,13 +80,13 @@ def _pd_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
                          scale, interpret: bool):
     B, H, hd = q.shape
-    NP, page, KV, _ = k_pages.shape
+    NP, KV, page, _ = k_pages.shape
     MP = page_table.shape[1]
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
@@ -98,21 +95,25 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
         return (b, kv, 0, 0)
 
     def kv_map(b, kv, p, tbl, lens):
-        return (tbl[b, p], 0, kv, 0)
+        return (tbl[b, p], kv, 0, 0)
 
     def o_map(b, kv, p, tbl, lens):
         return (b, kv, 0, 0)
 
-    grid_spec = _GridSpec(
+    # m/l scratch keep a trailing unit dim: 2-D VMEM refs tile cleanly,
+    # where a 1-D (G,) ref would put G on the lane axis
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, MP),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, page, hd), kv_map),
+            pl.BlockSpec((1, 1, page, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), o_map),
-        scratch_shapes=[_SCRATCH((G,)), _SCRATCH((G,)), _SCRATCH((G, hd))],
+        scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, hd), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_pd_kernel, scale=scale, page=page, n_pages=MP),
@@ -190,7 +191,7 @@ def paged_append(k_pages, v_pages, k_new, v_new, page_table, positions,
                  active: Optional[jnp.ndarray] = None):
     """Write one token's K/V into the shared pools (Scatter-Data half).
 
-    k_pages/v_pages: [NP, page, KV, hd]; k_new/v_new: [B, KV, hd];
+    k_pages/v_pages: [NP, KV, page, hd]; k_new/v_new: [B, KV, hd];
     page_table: [B, MP]; positions: [B] slot each token lands at.
     ``active`` [B] bool: inactive (parked) slots' writes are *dropped* —
     routed to an out-of-range page id — so a frozen sequence can never
@@ -198,15 +199,18 @@ def paged_append(k_pages, v_pages, k_new, v_new, page_table, positions,
     isolation).  Pages are exclusively owned, so the batched scatter is
     conflict-free by construction.
     """
-    NP, page, _, _ = k_pages.shape
+    NP, _, page, _ = k_pages.shape
     B = positions.shape[0]
     bidx = jnp.arange(B)
     pid = page_table[bidx, positions // page]          # [B]
     off = positions % page
     if active is not None:
         pid = jnp.where(active, pid, NP)               # out of range -> drop
-    k_pages = k_pages.at[pid, off].set(
+    # [pid, :, off] indexes one token row across every KV head; numpy
+    # advanced-index rules put the batch dim first, so the update is
+    # exactly k_new's [B, KV, hd]
+    k_pages = k_pages.at[pid, :, off].set(
         k_new.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[pid, off].set(
+    v_pages = v_pages.at[pid, :, off].set(
         v_new.astype(v_pages.dtype), mode="drop")
     return k_pages, v_pages

@@ -32,20 +32,21 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
 
 def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths,
                                scale=None):
-    """q: [B,H,hd]; pages: [NP,page,KV,hd]; table: [B,MP]; lengths: [B]."""
+    """q: [B,H,hd]; pages: [NP,KV,page,hd]; table: [B,MP]; lengths: [B]."""
     B, H, hd = q.shape
-    NP, page, KV, _ = k_pages.shape
+    NP, KV, page, _ = k_pages.shape
     MP = page_table.shape[1]
     G = H // KV
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
-    k = k_pages[page_table].reshape(B, MP * page, KV, hd)
-    v = v_pages[page_table].reshape(B, MP * page, KV, hd)
+    # [B, MP, KV, page, hd] -> [B, KV, MP*page, hd]: token order per head
+    k = jnp.moveaxis(k_pages[page_table], 2, 1).reshape(B, KV, MP * page, hd)
+    v = jnp.moveaxis(v_pages[page_table], 2, 1).reshape(B, KV, MP * page, hd)
     qg = q.reshape(B, KV, G, hd)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k).astype(jnp.float32) * scale
     valid = jnp.arange(MP * page)[None] < lengths[:, None]
     s = jnp.where(valid[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
+    o = jnp.einsum("bkgs,bksd->bkgd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, H, hd).astype(q.dtype)
 

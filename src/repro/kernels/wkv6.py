@@ -12,12 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
@@ -167,7 +162,7 @@ def wkv6_chunked(r, k, v, logw, u, state0, *, chunk: int = 32,
             jax.ShapeDtypeStruct((B * H, Sp, hd), jnp.float32),
             jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32),
         ],
-        scratch_shapes=[_SCRATCH((hd, hd))],
+        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
     )(rf, kf, vf, lwf, uf, s0f)
     y = y.reshape(B, H, Sp, hd).transpose(0, 2, 1, 3)[:, :S]

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import CONFIGS, get_config
 from repro.core.timing import Timer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.shapes import SHAPES_BY_NAME, applicable_shapes
 from repro.launch.mesh import make_production_mesh
 from repro.models import lm
@@ -153,6 +154,7 @@ def main():
     ap.add_argument("--out", default=str(RESULTS_DIR))
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)

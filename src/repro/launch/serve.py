@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_NAMES, get_config
 from repro.core.timing import DEFAULT_CLOCK, Timer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve.api import (EngineConfig, Request, SamplingParams,
                              default_page_budget, make_engine,
@@ -227,6 +228,7 @@ def main():
                          "--snapshot-dir before serving; new requests "
                          "get ids after the restored ones")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))

@@ -20,6 +20,7 @@ from repro.checkpoint import Checkpointer, latest_step
 from repro.core.timing import Timer
 from repro.configs.registry import ARCH_NAMES, get_config
 from repro.data import DataConfig, SyntheticPackedDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import lm
 from repro.optim import AdamWConfig, adamw_init
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_smoke_mesh() if args.smoke else make_production_mesh(

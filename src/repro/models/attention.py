@@ -264,7 +264,7 @@ def paged_decode_attention(q, page_table, k_pages, v_pages, lengths, *,
     """Decode attention through a page table (Resource Subsystem path).
 
     q: [B,H,hd]; page_table: [B,MP] int32 page ids; k_pages/v_pages:
-    [NP,page,KV,hd] shared page pools; lengths: [B].
+    [NP,KV,page,hd] shared page pools; lengths: [B].
     The gather of pages is the paper's Gather-Data primitive: KV for one
     sequence is scattered across the shared pool exactly as a NIC gathers a
     message from non-contiguous host buffers. Dispatches to the Pallas
@@ -273,7 +273,7 @@ def paged_decode_attention(q, page_table, k_pages, v_pages, lengths, *,
     from repro.kernels import paged_attention as pk
     if policy is not None:
         q = policy.constrain(q, "batch", "heads", None)
-        k_pages = policy.constrain(k_pages, "pages", None, "kv_heads", None)
-        v_pages = policy.constrain(v_pages, "pages", None, "kv_heads", None)
+        k_pages = policy.constrain(k_pages, "pages", "kv_heads", None, None)
+        v_pages = policy.constrain(v_pages, "pages", "kv_heads", None, None)
     return pk.paged_decode_attention(q, k_pages, v_pages, page_table,
                                      lengths, scale=scale, backend="auto")

@@ -18,18 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-    _SM_REP_KWARG = "check_vma"
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_REP_KWARG = "check_rep"
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SM_REP_KWARG: check_rep})
-
 from repro.configs.base import ModelConfig
 from repro.kernels import sampling as ksamp
 from repro.models import transformer as tf
@@ -112,12 +100,12 @@ def embed(table, ids, policy: Policy):
         return jax.lax.psum(out, tp)
 
     nd = ids.ndim
-    return shard_map(
+    return jax.shard_map(
         body, mesh=policy.mesh,
         in_specs=(P(tp, fsdp_ax if use_fsdp else None),
                   P(dp, *([None] * (nd - 1)))),
         out_specs=P(dp, *([None] * nd)),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 
@@ -194,13 +182,13 @@ def chunked_ce_loss(x, head_w, targets, mask, policy: Policy,
         cnt = jax.lax.psum(cnt, dp)
         return (tot / jnp.maximum(cnt, 1.0))[None]
 
-    loss = shard_map(
+    loss = jax.shard_map(
         body, mesh=policy.mesh,
         in_specs=(P(dp, None, None),
                   P(fsdp_ax if use_fsdp else None, tp),
                   P(dp, None), P(dp, None)),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )(x, head_w, targets, mask.astype(jnp.float32))
     return loss[0]
 
@@ -426,7 +414,7 @@ def init_paged_serve_state(cfg: ModelConfig, batch: int, n_pages: int,
                            tp: int = 1) -> dict:
     """Paged decoding state: shared per-layer page pools + per-slot MTT.
 
-    ``caches`` leaves are [n_pages, page_size, KV, hd] pools (plain
+    ``caches`` leaves are [n_pages, KV, page_size, hd] pools (plain
     attention) or [n_pages, page_size, lora|rope] latent pools (MLA)
     shared by all `batch` slots; ``page_table`` [batch, max_pages] names
     each slot's pages in token order (rows are rewritten by the engine as

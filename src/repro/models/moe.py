@@ -74,7 +74,6 @@ def moe_mlp(x: jnp.ndarray, p: dict, cfg: ModelConfig, policy,
 
 
 def _moe_mlp_sharded(x, p, cfg, policy, capacity_factor):
-    from repro.models.lm import shard_map   # version-compat shim
     from jax.sharding import PartitionSpec as P
     moe = cfg.moe
     dp, tp = policy.dp_axes, policy.tp_axis
@@ -121,11 +120,12 @@ def _moe_mlp_sharded(x, p, cfg, policy, capacity_factor):
         return out, stats
 
     g_spec = P(dp, None, None) if dp else P(None, None, None)
-    out, stats = shard_map(
+    out, stats = jax.shard_map(
         body, mesh=policy.mesh,
         in_specs=(g_spec, P(None, None),
                   w_spec("w_gate"), w_spec("w_up"), w_spec("w_down")),
         out_specs=(g_spec, P()),
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if moe.n_shared:
         out = out + dense_mlp(x, p["shared"], cfg, policy)

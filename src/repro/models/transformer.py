@@ -192,7 +192,7 @@ def attn_prefill_chunk(x, p, cfg: ModelConfig, policy, ctx, cache):
 def attn_decode_paged(x, p, cfg: ModelConfig, policy, ctx, cache):
     """Paged decode: KV lives in a shared page pool, not a per-slot slab.
 
-    x: [B,D]; cache {k,v: [NP,page,KV,hd]} — the *pool*, shared by every
+    x: [B,D]; cache {k,v: [NP,KV,page,hd]} — the *pool*, shared by every
     slot; ctx carries positions/lengths [B] and page_table [B,MP] (the MTT
     row per slot, exported by core.resource.PagePool). The new token's K/V
     is scattered into its owning page (parked slots' writes are dropped —
@@ -550,7 +550,10 @@ def init_stack_caches(cfg: ModelConfig, batch: int, cache_len: int, dtype,
 
 def init_paged_stack_caches(cfg: ModelConfig, n_pages: int, page_size: int,
                             dtype, tp: int = 1) -> dict:
-    """Shared-pool caches: every attn layer holds [NP, page, KV, hd] pools.
+    """Shared-pool caches: every attn layer holds [NP, KV, page, hd] pools.
+
+    KV-head-major, so each (page, head) is one contiguous (page, hd) tile
+    — the block the Pallas decode kernel streams per grid step.
 
     Unlike init_stack_caches there is no per-slot batch dim — all serving
     slots share one fixed block of page memory per layer and are separated
@@ -562,8 +565,8 @@ def init_paged_stack_caches(cfg: ModelConfig, n_pages: int, page_size: int,
     hd = cfg.head_dim
 
     def one_pool():
-        return {"k": jnp.zeros((n_pages, page_size, KV, hd), dtype),
-                "v": jnp.zeros((n_pages, page_size, KV, hd), dtype)}
+        return {"k": jnp.zeros((n_pages, KV, page_size, hd), dtype),
+                "v": jnp.zeros((n_pages, KV, page_size, hd), dtype)}
 
     prefix, unit, n_groups = plan_layers(cfg)
     caches: Dict[str, Any] = {"prefix": [], "groups": None}
@@ -624,12 +627,15 @@ def recurrent_state_supported(cfg: ModelConfig) -> bool:
 
 # -- page-granular cache movement (engine: prefill insert, park/unpark) -----
 #
-# Pool leaves are [NP, page, ...] (prefix blocks) or [G, NP, page, ...]
-# (group-scanned blocks); whether a leaf carries the leading group axis is
-# decided by which subtree it sits in — NOT by ndim, so the same maps move
-# attention pages ([..., KV, hd] tails) and MLA latent pages ([..., lora] /
-# [..., rope] tails). These tree maps are the engine's only way to touch
-# pool memory: everything moves page-by-page, never as per-slot slabs.
+# Pool leaves are [NP, *heads, page, feat] (prefix blocks) or
+# [G, NP, *heads, page, feat] (group-scanned blocks): the token-in-page
+# axis sits just before the feature axis, so attention pages are
+# [NP, KV, page, hd] and MLA latent pages [NP, page, lora] / [NP, page,
+# rope]. Dense caches keep tokens at axis 1 ([1, L, *heads, feat]), so a
+# dense<->page move is a reshape plus one axis move. Whether a leaf
+# carries the leading group axis is decided by which subtree it sits in —
+# NOT by ndim. These tree maps are the engine's only way to touch pool
+# memory: everything moves page-by-page, never as per-slot slabs.
 
 def _map_stack(cache, fn):
     """Apply ``fn(leaf, grouped)`` across a stack-cache tree, tagging
@@ -655,24 +661,23 @@ def _map_stack2(cache, other, fn):
     return out
 
 
-def dense_to_pages(dense_caches, n_pages: int, page_size: int):
+def dense_to_pages(dense_caches, n_pages: int, page_size: int,
+                   first: int = 0):
     """Chunk a batch-1 dense cache tree into page-granular data.
 
-    dense leaves [1, L, ...] -> [n_pages, page, ...] (grouped leaves keep
-    their leading G). Requires L >= n_pages*page_size (prefill pads to
+    dense leaves [1, L, *heads, feat] -> pages ``first .. first+n_pages``
+    as [n_pages, *heads, page, feat] (grouped leaves keep their leading
+    G). Requires L >= (first+n_pages)*page_size (prefill pads to
     cache_len, so the tail pages beyond `length` are zeros — masked out
     by `lengths` at attention time).
     """
     def one(dense, grouped):
-        if grouped:                               # [G, 1, L, ...]
-            G, _, L = dense.shape[:3]
-            tail = dense.shape[3:]
-            return dense[:, 0].reshape(
-                (G, L // page_size, page_size) + tail)[:, :n_pages]
-        _, L = dense.shape[:2]                    # [1, L, ...]
-        tail = dense.shape[2:]
-        return dense[0].reshape(
-            (L // page_size, page_size) + tail)[:n_pages]
+        d = dense[:, 0] if grouped else dense[0]  # [(G,) L, *heads, feat]
+        t = 1 if grouped else 0                   # token axis
+        d = jax.lax.slice_in_dim(d, first * page_size,
+                                 (first + n_pages) * page_size, axis=t)
+        d = d.reshape(d.shape[:t] + (n_pages, page_size) + d.shape[t + 1:])
+        return jnp.moveaxis(d, t + 1, -2)
     return _map_stack(dense_caches, one)
 
 
@@ -680,25 +685,19 @@ def pages_to_dense(page_caches, cache_len: int, page_size: int):
     """Inverse of ``dense_to_pages``: page-granular data (token order) back
     to a batch-1 dense cache tree zero-padded to ``cache_len``.
 
-    page leaves [P, page, ...] -> [1, cache_len, ...] (grouped leaves
-    [G, P, page, ...] -> [G, 1, cache_len, ...]). Used by the
-    chunked-prefill path to stage a paged slot's prefix as the dense cache
+    page leaves [P, *heads, page, feat] -> [1, cache_len, *heads, feat]
+    (grouped leaves keep their leading G). Used by the chunked-prefill
+    path to stage a paged slot's prefix as the dense cache
     `attn_prefill_chunk` extends.
     """
     def one(p, grouped):
-        if grouped:                               # [G, P, page, ...]
-            G, P = p.shape[:2]
-            tail = p.shape[3:]
-            d = p.reshape((G, P * page_size) + tail)
-            d = jnp.pad(d, ((0, 0), (0, cache_len - P * page_size))
-                        + ((0, 0),) * len(tail))
-            return d[:, None]
-        P = p.shape[0]                            # [P, page, ...]
-        tail = p.shape[2:]
-        d = p.reshape((P * page_size,) + tail)
-        d = jnp.pad(d, ((0, cache_len - P * page_size),)
-                    + ((0, 0),) * len(tail))
-        return d[None]
+        t = 1 if grouped else 0                   # page axis
+        d = jnp.moveaxis(p, -2, t + 1)            # [(G,) P, page, ..., feat]
+        P = d.shape[t]
+        d = d.reshape(d.shape[:t] + (P * page_size,) + d.shape[t + 2:])
+        pad = [(0, 0)] * d.ndim
+        pad[t] = (0, cache_len - P * page_size)
+        return jnp.expand_dims(jnp.pad(d, pad), t)
     return _map_stack(page_caches, one)
 
 

@@ -81,11 +81,37 @@ SNAPSHOT_VERSION = 1
 _COMPILE_CACHE: dict = {}
 
 
-def _cached_jit(key, make):
+def _cached_jit(key, make, donate_argnums=()):
     fn = _COMPILE_CACHE.get(key)
     if fn is None:
-        fn = _COMPILE_CACHE[key] = jax.jit(make())
+        fn = _COMPILE_CACHE[key] = jax.jit(make(),
+                                           donate_argnums=donate_argnums)
     return fn
+
+
+def span_program(cfg: ModelConfig, policy: Policy, ecfg: EngineConfig,
+                 sampler: Sampler, span: int, want_lp: bool):
+    """The jitted fused-decode scan for one executed span length, with
+    ``sampler`` closed over as the per-step selection handler (DESIGN.md
+    §3.7). Called as ``fn(params, tokens, state, active, budgets,
+    sampler_params, rng)``. Executed lengths are pow2-bucketed (capped at
+    decode_span), so shrunken spans cost at most log2(decode_span) extra
+    compiles (×2 when logprobs are on), shared across engines through the
+    module compile cache.
+
+    The decode state (argument 2) is donated: the span returns the whole
+    updated state, so its K/V pools are updated in place instead of
+    holding an old and a new copy of every pool at once. Callers must
+    drop the state they passed in and keep the returned one."""
+    eos, L = ecfg.eos_token, ecfg.cache_len
+    sample = sampler.sample
+    return _cached_jit(
+        ("span", id(cfg), id(policy), eos, L, type(sampler), span, want_lp),
+        lambda: lambda p, t, s, a, b, sp, rng: lm.decode_span(
+            p, t, s, cfg, policy, a, b, span=span, eos_token=eos,
+            cache_len=L, sample_fn=sample, sampler_params=sp,
+            rng=rng, want_logprobs=want_lp),
+        donate_argnums=(2,))
 
 
 class ServingEngine:
@@ -626,25 +652,6 @@ class ServingEngine:
         self.stats["preempt_restarts"] += 1
 
     # -- decode spans (DESIGN.md §3.6) -------------------------------------
-    def _span_fn(self, span: int, want_lp: bool):
-        """The jitted fused-decode scan for one executed span length,
-        with the engine's sampler closed over as the per-step selection
-        handler (DESIGN.md §3.7). One compiled scan per executed span
-        length; lengths are pow2-bucketed (capped at decode_span) so
-        shrunken spans cost at most log2(decode_span) extra compiles
-        (×2 when logprobs are on) — shared across engines through the
-        module compile cache."""
-        cfg, policy = self.cfg, self.policy
-        eos, L = self.ecfg.eos_token, self.ecfg.cache_len
-        sample = self.sampler.sample
-        return _cached_jit(
-            ("span", id(cfg), id(policy), eos, L, type(self.sampler),
-             span, want_lp),
-            lambda: lambda p, t, s, a, b, sp, rng: lm.decode_span(
-                p, t, s, cfg, policy, a, b, span=span, eos_token=eos,
-                cache_len=L, sample_fn=sample, sampler_params=sp,
-                rng=rng, want_logprobs=want_lp))
-
     @staticmethod
     def _slot_pos(req: Request) -> int:
         """A decoding slot's device position, from host bookkeeping alone
@@ -743,7 +750,8 @@ class ServingEngine:
                 tokens[i] = req.tokens_out[-1]
         want_lp = any(r is not None and r.sampling.logprobs
                       for r in self.slot_req)
-        out = self._span_fn(span_exec, want_lp)(
+        out = span_program(self.cfg, self.policy, self.ecfg, self.sampler,
+                           span_exec, want_lp)(
             self.params, jnp.asarray(tokens), self.state,
             jnp.asarray(act), jnp.asarray(budgets),
             self._sampler_params(self.slot_req),

@@ -6,7 +6,7 @@ the frame fixed while the resource tier swaps layouts:
 
 - `DenseKV` ("dense"): per-slot `[slots, cache_len, KV, hd]` slabs.
   Serves every architecture (init_stack_caches is kind-generic).
-- `PagedKV` ("paged"): shared `[n_pages, page_size, KV, hd]` pool behind
+- `PagedKV` ("paged"): shared `[n_pages, KV, page_size, hd]` pool behind
   per-slot page tables (the MTT made into the actual memory layout).
 - `LatentPagedKV` ("latent"): MLA's absorbed-decode cache behind the
   same MTT — `[kv_lora_rank + qk_rope_dim]` bytes per token instead of
@@ -354,16 +354,7 @@ class PagedKV(_PooledKV):
         ps = self.ecfg.page_size
         p0, p1 = start // ps, -(-(start + n_tokens) // ps)
         pages = self.pool.pages_of(req_id)[p0:p1]
-
-        def cut(leaf):
-            if leaf.ndim == 5:                    # [G, 1, L, KV, hd]
-                seg = leaf[:, 0, p0 * ps:p1 * ps]
-                return seg.reshape((leaf.shape[0], len(pages), ps)
-                                   + leaf.shape[3:])
-            seg = leaf[0, p0 * ps:p1 * ps]        # [1, L, KV, hd]
-            return seg.reshape((len(pages), ps) + leaf.shape[2:])
-
-        data = jax.tree.map(cut, caches)
+        data = tf.dense_to_pages(caches, len(pages), ps, first=p0)
         state["caches"] = tf.scatter_pages(state["caches"], data, pages)
         self._dirty = True
         return state

@@ -2,8 +2,11 @@ import os
 import sys
 
 # tests see exactly 1 device (the dry-run sets its own XLA_FLAGS; never set
-# the 512-device override globally)
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the 512-device override globally). The repo root is on the path for
+# chip_smoke.py's check functions.
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+sys.path.insert(0, os.path.join(ROOT, "src"))
+sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
 

@@ -287,8 +287,8 @@ def test_bounded_table_matches_full_width_both_backends():
     still matches the Pallas kernel at the narrowed width."""
     rng = np.random.default_rng(11)
     NP, page, KV, hd, B, H = 16, 4, 2, 8, 2, 4
-    kp = jnp.asarray(rng.normal(size=(NP, page, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(NP, page, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NP, KV, page, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NP, KV, page, hd)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     pool = PagePool(n_pages=NP, page_size=page)
     pool.alloc(99, 2)                        # non-trivial page ids

@@ -67,17 +67,22 @@ def test_stream_matches_tokens_out(tiny, layout, span, chunk):
 @pytest.mark.parametrize("layout", ["dense", "paged"])
 def test_stream_invariant_to_span_and_chunking(tiny, layout):
     """The streamed sequence itself is the same whether tokens arrived
-    one per sync or eight per sync, chunked or monolithic prefill."""
+    one per sync or eight per sync, under monolithic and under chunked
+    prefill. Tokens are compared only across bit-identical variations:
+    chunked and monolithic prefill agree on logits to the tolerance
+    tests/test_chunked_prefill.py pins, not bit for bit, so a near-tie
+    may rightly pick a different greedy token between the two modes."""
     cfg, params = tiny
     streams = {}
-    for span, chunk in ((1, 0), (8, 0), (8, 8)):
+    for span, chunk in ((1, 0), (8, 0), (1, 8), (8, 8)):
         _, eng, fe = _stack(cfg, params, kv_layout=layout,
                             decode_span=span, prefill_chunk=chunk)
         hs = [fe.submit(Request(i, p, max_new_tokens=6))
               for i, p in enumerate(_prompts(cfg, 3))]
         fe.run()
         streams[(span, chunk)] = [h.streamed for h in hs]
-    assert streams[(1, 0)] == streams[(8, 0)] == streams[(8, 8)]
+    assert streams[(1, 0)] == streams[(8, 0)]
+    assert streams[(1, 8)] == streams[(8, 8)]
 
 
 def test_stream_survives_park_unpark_midstream(tiny):

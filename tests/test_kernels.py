@@ -57,8 +57,8 @@ def test_flash_attention_swa(window):
 def test_paged_decode(B, H, KV, hd, NP, page, MP, dtype):
     ks = jax.random.split(jax.random.PRNGKey(NP + MP), 5)
     q = _rand(ks[0], (B, H, hd), dtype)
-    kp = _rand(ks[1], (NP, page, KV, hd), dtype)
-    vp = _rand(ks[2], (NP, page, KV, hd), dtype)
+    kp = _rand(ks[1], (NP, KV, page, hd), dtype)
+    vp = _rand(ks[2], (NP, KV, page, hd), dtype)
     table = jax.random.randint(ks[3], (B, MP), 0, NP)
     lengths = jax.random.randint(ks[4], (B,), 1, MP * page + 1)
     out = ops.paged_decode_attention(q, kp, vp, table, lengths,

@@ -218,8 +218,8 @@ def test_paged_state_rejects_non_attention():
 
 def test_paged_append_drops_parked_writes():
     NP, ps, KV, hd, B = 4, 4, 2, 8, 3
-    kp = jnp.zeros((NP, ps, KV, hd))
-    vp = jnp.zeros((NP, ps, KV, hd))
+    kp = jnp.zeros((NP, KV, ps, hd))
+    vp = jnp.zeros((NP, KV, ps, hd))
     k_new = jnp.ones((B, KV, hd))
     v_new = 2 * jnp.ones((B, KV, hd))
     table = jnp.asarray([[1, 0], [2, 0], [3, 0]], jnp.int32)
@@ -228,8 +228,9 @@ def test_paged_append_drops_parked_writes():
     kp2, vp2 = paged_append(kp, vp, k_new, v_new, table, positions,
                             active=active)
     assert float(kp2[1, 0, 0, 0]) == 1.0     # slot 0 wrote page 1, off 0
-    assert float(kp2[2, 1, 0, 0]) == 0.0     # slot 1 parked: dropped
-    assert float(kp2[3, 2, 0, 0]) == 1.0     # slot 2 wrote page 3, off 2
+    assert float(kp2[2, 0, 1, 0]) == 0.0     # slot 1 parked: dropped
+    assert float(kp2[3, 1, 2, 0]) == 1.0     # slot 2 wrote page 3, off 2
+    assert float(vp2[3, 1, 2, 0]) == 2.0     # every KV head of that row
     assert float(jnp.sum(jnp.abs(kp2))) == pytest.approx(
         2 * KV * hd)                          # nothing else touched
 

@@ -94,9 +94,8 @@ cfg = SMOKE_CONFIGS["moonshot-v1-16b-a3b"]
 params = lm.init_params(cfg, jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0, cfg.vocab_size)
 l0, _ = jax.jit(lambda p, t: lm.forward_loss(p, t, cfg, NULL_POLICY))(params, toks)
-at = getattr(jax.sharding, "AxisType", None)
 mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     **({"axis_types": (at.Auto,) * 2} if at else {}))
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 pol = make_policy(mesh)
 with mesh:
     l1, _ = jax.jit(lambda p, t: lm.forward_loss(p, t, cfg, pol))(params, toks)
