@@ -120,6 +120,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, qg, k_pages, v_pages)
     return out.reshape(B, H, hd)
 

@@ -360,7 +360,9 @@ def decode_span(params, tokens, state, cfg: ModelConfig, policy: Policy,
         else:
             keys = (ksamp.derive_keys(seeds, req_ids, ctr)
                     if rng is not None else None)
-            nxt = sample_fn(logits, keys, sampler_params).astype(jnp.int32)
+            with jax.named_scope("sampler"):
+                nxt = sample_fn(logits, keys,
+                                sampler_params).astype(jnp.int32)
         nxt = jnp.where(act, nxt, toks)
         out = (nxt, act)
         if want_logprobs:
@@ -392,7 +394,8 @@ def select_token(logits, sample_fn=None, sampler_params=None, rng=None):
     if sample_fn is None:
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
-        tok = sample_fn(logits, keys, sampler_params).astype(jnp.int32)
+        with jax.named_scope("sampler"):
+            tok = sample_fn(logits, keys, sampler_params).astype(jnp.int32)
     return tok, ksamp.token_logprob(logits, tok)
 
 

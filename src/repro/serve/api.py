@@ -81,6 +81,11 @@ class Request:
     finished_at: Optional[float] = None
     sampling: SamplingParams = field(default_factory=SamplingParams)
     logprobs_out: List[float] = field(default_factory=list)
+    # lifecycle stamps on the engine's clock, each set once: when the
+    # request first got a slot (`_admit`) and when its first token was
+    # emitted (`_emit`); a preempt-restart or an unpark moves neither
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     # streaming hooks (DESIGN.md §3.8): the engine invokes `on_tokens`
     # with the freshly appended token batch at each host-sync point (one
     # per prefill completion, one per decode span — never more), and
@@ -145,6 +150,10 @@ class ParkMeta(NamedTuple):
     n_pages: int                  # 0 for layouts without page indirection
 
 
+def _opt_float(x) -> Optional[float]:
+    return None if x is None else float(x)
+
+
 def request_to_state(req: Request) -> dict:
     """JSON-able snapshot of a Request (DESIGN.md §9).
 
@@ -162,11 +171,12 @@ def request_to_state(req: Request) -> dict:
         "qos": int(req.qos),
         "arrived_at": float(req.arrived_at),
         "tokens_out": [int(t) for t in req.tokens_out],
-        "finished_at": (None if req.finished_at is None
-                        else float(req.finished_at)),
+        "finished_at": _opt_float(req.finished_at),
         "sampling": [float(s.temperature), int(s.top_k), float(s.top_p),
                      int(s.seed), bool(s.logprobs)],
         "logprobs_out": [float(x) for x in req.logprobs_out],
+        "admitted_at": _opt_float(req.admitted_at),
+        "first_token_at": _opt_float(req.first_token_at),
     }
 
 
@@ -179,11 +189,12 @@ def request_from_state(d: dict) -> Request:
         qos=int(d["qos"]),
         arrived_at=float(d["arrived_at"]),
         tokens_out=[int(t) for t in d["tokens_out"]],
-        finished_at=(None if d["finished_at"] is None
-                     else float(d["finished_at"])),
+        finished_at=_opt_float(d["finished_at"]),
         sampling=SamplingParams(float(temp), int(top_k), float(top_p),
                                 int(seed), bool(logprobs)),
-        logprobs_out=[float(x) for x in d["logprobs_out"]])
+        logprobs_out=[float(x) for x in d["logprobs_out"]],
+        admitted_at=_opt_float(d.get("admitted_at")),
+        first_token_at=_opt_float(d.get("first_token_at")))
 
 
 # --------------------------------------------------------------------------

@@ -38,6 +38,7 @@ per-step decode in both KV layouts.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 import jax
@@ -81,12 +82,22 @@ SNAPSHOT_VERSION = 1
 _COMPILE_CACHE: dict = {}
 
 
-def _cached_jit(key, make, donate_argnums=()):
+def _cached_jit(key, make, name, donate_argnums=()):
+    """``jax.jit`` of ``make()``, cached under ``key``. The function is
+    renamed ``name`` first, so the profiler's trace and the compiler name
+    the program ``jit_<name>`` instead of ``jit__lambda``."""
     fn = _COMPILE_CACHE.get(key)
     if fn is None:
-        fn = _COMPILE_CACHE[key] = jax.jit(make(),
-                                           donate_argnums=donate_argnums)
+        f = make()
+        f.__name__ = f.__qualname__ = name
+        fn = _COMPILE_CACHE[key] = jax.jit(f, donate_argnums=donate_argnums)
     return fn
+
+
+def _span(name: str):
+    """Method decorator: run the call inside the profiler span ``name``
+    (a ``TraceAnnotation``: one inactive check when no trace is open)."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 def span_program(cfg: ModelConfig, policy: Policy, ecfg: EngineConfig,
@@ -111,7 +122,7 @@ def span_program(cfg: ModelConfig, policy: Policy, ecfg: EngineConfig,
             p, t, s, cfg, policy, a, b, span=span, eos_token=eos,
             cache_len=L, sample_fn=sample, sampler_params=sp,
             rng=rng, want_logprobs=want_lp),
-        donate_argnums=(2,))
+        "serve_decode_span", donate_argnums=(2,))
 
 
 class ServingEngine:
@@ -205,14 +216,17 @@ class ServingEngine:
         sample = self.sampler.sample
         self._prefill = _cached_jit(
             ("prefill", id(cfg), id(policy), L),
-            lambda: lambda p, t: lm.prefill(p, t, cfg, policy, cache_len=L))
+            lambda: lambda p, t: lm.prefill(p, t, cfg, policy, cache_len=L),
+            "serve_prefill")
         self._prefill_chunk = _cached_jit(
             ("prefill_chunk", id(cfg), id(policy)),
             lambda: lambda p, t, c, s, nv: lm.prefill_chunk(
-                p, t, c, s, nv, cfg, policy))
+                p, t, c, s, nv, cfg, policy),
+            "serve_prefill_chunk")
         self._select_fn = _cached_jit(
             ("select", type(self.sampler)),
-            lambda: lambda lg, sp, rng: lm.select_token(lg, sample, sp, rng))
+            lambda: lambda lg, sp, rng: lm.select_token(lg, sample, sp, rng),
+            "serve_select")
 
     @property
     def pool(self):
@@ -222,6 +236,7 @@ class ServingEngine:
     def _streaming(self) -> bool:
         return bool(self.ecfg.prefill_chunk) and self._chunked_ok
 
+    @_span("serve.host_sync")
     def _host_sync(self, tree):
         """THE accounted blocking device->host transfer. Every read the
         serving loop makes off the device — one per decode span, one per
@@ -316,12 +331,15 @@ class ServingEngine:
         values from its one accounted sync. Streaming therefore costs
         zero extra host syncs: `on_tokens` observes exactly what
         `tokens_out` received, in the same order."""
+        if toks and req.first_token_at is None:
+            req.first_token_at = self.clock()
         req.tokens_out.extend(toks)
         if lps is not None and req.sampling.logprobs:
             req.logprobs_out.extend(lps)
         if req.on_tokens is not None and toks:
             req.on_tokens(req, toks)
 
+    @_span("serve.admit")
     def _admit(self) -> int:
         admitted = 0
         while True:
@@ -355,6 +373,8 @@ class ServingEngine:
                 self.prefix.unrecord(matched)    # retry will re-match
                 self._requeue(req)               # requeue; others proceed
                 break
+            if req.admitted_at is None:
+                req.admitted_at = self.clock()
             self.active[slot] = True
             self.running[slot] = False
             self.prefilling[slot] = True
@@ -374,6 +394,7 @@ class ServingEngine:
             admitted += 1
         return admitted
 
+    @_span("serve.prefill")
     def _prefill_full(self, slot: int, req: Request):
         """Monolithic prefill (chunking disabled / unsupported config)."""
         prompt = np.asarray(req.prompt, np.int32)
@@ -394,6 +415,7 @@ class ServingEngine:
         return int(tok[0]), float(lp[0])
 
     # -- chunked prefill (DESIGN.md §3.4) ---------------------------------
+    @_span("serve.prefill")
     def _prefill_step(self):
         """Stream page-aligned chunks of the PREFILLING slots' prompts,
         bounded by the per-step token budget — long prompts interleave
@@ -440,12 +462,14 @@ class ServingEngine:
             return 0
         chunk = np.zeros(width, np.int32)
         chunk[:n_valid] = np.asarray(req.prompt[pos:pos + n_valid], np.int32)
-        caches = self.kv.slot_caches(self.state, slot, req.req_id)
+        with jax.profiler.TraceAnnotation("serve.kv.stage"):
+            caches = self.kv.slot_caches(self.state, slot, req.req_id)
         logits, caches = self._prefill_chunk(
             self.params, jnp.asarray(chunk[None]), caches,
             jnp.int32(pos), jnp.int32(n_valid))
-        self.state = self.kv.store_chunk(
-            self.state, slot, req.req_id, caches, pos, n_valid)
+        with jax.profiler.TraceAnnotation("serve.kv.store"):
+            self.state = self.kv.store_chunk(
+                self.state, slot, req.req_id, caches, pos, n_valid)
         self.prefill_pos[slot] = pos + n_valid
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += n_valid
@@ -583,6 +607,7 @@ class ServingEngine:
             self.stats["unparked"] += 1
 
     # -- capacity growth ---------------------------------------------------
+    @_span("serve.grow")
     def _grow(self):
         """Alloc-on-append: claim a fresh page for every running slot whose
         next token crosses a page boundary. When the pool is dry and nobody
@@ -679,6 +704,7 @@ class ServingEngine:
         self.stats["span_shrinks"] += 1
         return got
 
+    @_span("serve.reserve")
     def _reserve_decode_span(self, act: np.ndarray):
         """Per-slot span budgets + the executed span length.
 
@@ -728,6 +754,7 @@ class ServingEngine:
             # in PagePool.alloc, where every page claim funnels
             self.stats["pages_peak"] = self.pool.peak
 
+    @_span("serve.step")
     def _step(self):
         self._admit()
         self._try_unpark()
@@ -739,23 +766,25 @@ class ServingEngine:
             # reserve before sync: headroom pages must be in the exported
             # tables the scan chases
             budgets, span_exec = self._reserve_decode_span(act)
-        self.state = self.kv.sync(
-            self.state,
-            [r.req_id if r is not None else None for r in self.slot_req])
+        with jax.profiler.TraceAnnotation("serve.kv.sync"):
+            self.state = self.kv.sync(
+                self.state,
+                [r.req_id if r is not None else None for r in self.slot_req])
         if not act.any():
             return                           # only prefilling/parked slots
-        tokens = np.zeros(self.ecfg.slots, np.int32)
-        for i, req in enumerate(self.slot_req):
-            if req is not None and req.tokens_out:
-                tokens[i] = req.tokens_out[-1]
-        want_lp = any(r is not None and r.sampling.logprobs
-                      for r in self.slot_req)
-        out = span_program(self.cfg, self.policy, self.ecfg, self.sampler,
-                           span_exec, want_lp)(
-            self.params, jnp.asarray(tokens), self.state,
-            jnp.asarray(act), jnp.asarray(budgets),
-            self._sampler_params(self.slot_req),
-            self._sampler_rng(self.slot_req))
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            tokens = np.zeros(self.ecfg.slots, np.int32)
+            for i, req in enumerate(self.slot_req):
+                if req is not None and req.tokens_out:
+                    tokens[i] = req.tokens_out[-1]
+            want_lp = any(r is not None and r.sampling.logprobs
+                          for r in self.slot_req)
+            out = span_program(self.cfg, self.policy, self.ecfg,
+                               self.sampler, span_exec, want_lp)(
+                self.params, jnp.asarray(tokens), self.state,
+                jnp.asarray(act), jnp.asarray(budgets),
+                self._sampler_params(self.slot_req),
+                self._sampler_rng(self.slot_req))
         if want_lp:
             toks, emit, lps, self.state = out
         else:
@@ -769,21 +798,23 @@ class ServingEngine:
         got = self._host_sync((toks, emit) if lps is None
                               else (toks, emit, lps))
         toks, emit, lps = got if lps is not None else (*got, None)
-        for i in range(self.ecfg.slots):
-            req = self.slot_req[i]
-            if req is None or not act[i]:
-                continue
-            new = [int(t) for t in toks[emit[:, i], i]]  # slot i's
-            #                                       emissions, in order
-            self._emit(req, new,
-                       None if lps is None
-                       else [float(x) for x in lps[emit[:, i], i]])
-            self.stats["decode_tokens"] += len(new)
-            done = (len(req.tokens_out) >= req.max_new_tokens
-                    or (len(new) and int(new[-1]) == self.ecfg.eos_token)
-                    or self._slot_pos(req) >= self.ecfg.cache_len)
-            if done:
-                self._complete(i, req)
+        with jax.profiler.TraceAnnotation("serve.emit"):
+            for i in range(self.ecfg.slots):
+                req = self.slot_req[i]
+                if req is None or not act[i]:
+                    continue
+                new = [int(t) for t in toks[emit[:, i], i]]  # slot i's
+                #                                       emissions, in order
+                self._emit(req, new,
+                           None if lps is None
+                           else [float(x) for x in lps[emit[:, i], i]])
+                self.stats["decode_tokens"] += len(new)
+                done = (len(req.tokens_out) >= req.max_new_tokens
+                        or (len(new)
+                            and int(new[-1]) == self.ecfg.eos_token)
+                        or self._slot_pos(req) >= self.ecfg.cache_len)
+                if done:
+                    self._complete(i, req)
 
     def run_until_done(self, max_steps: int = 10_000):
         """Drive the engine until every submitted request completes.

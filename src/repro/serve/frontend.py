@@ -82,7 +82,6 @@ class RequestHandle:
         self.degraded = False
         self.streamed: List[int] = []
         self.submitted_at = clock()
-        self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
 
     # -- stream side (wired to Request.on_tokens by the frontend) ------
@@ -91,8 +90,6 @@ class RequestHandle:
         for k, tok in enumerate(new):
             if start + k < len(self.streamed):
                 continue      # preempt-restart replay of delivered tokens
-            if self.first_token_at is None:
-                self.first_token_at = self._clock()
             self.streamed.append(int(tok))
             if self.on_token is not None:
                 self.on_token(int(tok), len(self.streamed) - 1)
@@ -110,6 +107,12 @@ class RequestHandle:
     @property
     def ok(self) -> bool:
         return self.outcome == OUTCOME_COMPLETED
+
+    @property
+    def first_token_at(self) -> Optional[float]:
+        """When the engine emitted the request's first token (the
+        request's own stamp, on the same clock)."""
+        return self.req.first_token_at
 
     @property
     def ttft(self) -> Optional[float]:
@@ -306,6 +309,10 @@ class LocalFrontend:
         for rid, h in list(self._handles.items()):
             req = live.get(rid)
             if req is not None:
+                # a token streamed after the snapshot was taken keeps its
+                # stamp: the restored request was copied before it
+                if req.first_token_at is None:
+                    req.first_token_at = h.req.first_token_at
                 h.req = req
                 req.on_tokens = h._feed
                 req.on_done = self._on_done
