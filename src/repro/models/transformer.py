@@ -206,9 +206,16 @@ def attn_decode_paged(x, p, cfg: ModelConfig, policy, ctx, cache):
     ang = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q[:, None], ang[:, None])[:, 0]
     k_new = apply_rope(k_new[:, None], ang[:, None])[:, 0]
+    active = ctx.get("active")
     k_p, v_p = paged_append(cache["k"], cache["v"], k_new, v_new, table,
-                            positions, active=ctx.get("active"))
-    out = paged_decode_attention(q, table, k_p, v_p, lengths + 1,
+                            positions, active=active)
+    lengths = lengths + 1
+    if active is not None:
+        # a slot outside the batch (free, prefilling, parked, stopped
+        # mid-span) keeps a stale length and its output is dropped:
+        # give it none, so the kernel fetches none of its pages
+        lengths = jnp.where(active, lengths, 0)
+    out = paged_decode_attention(q, table, k_p, v_p, lengths,
                                  policy=policy)
     out = out.reshape(x.shape[0], -1) @ p["wo"]
     return out, {"k": k_p, "v": v_p}

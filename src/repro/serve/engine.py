@@ -207,7 +207,8 @@ class ServingEngine:
                       "parked": 0, "unparked": 0,
                       "prefix_hits": 0, "prefix_tokens_reused": 0,
                       "page_allocs": 0, "pages_peak": 0,
-                      "preempt_restarts": 0}
+                      "preempt_restarts": 0,
+                      "attn_pages_table": 0, "attn_pages_live": 0}
 
         # compiled entry points come from the module-level _COMPILE_CACHE
         # so engine rebuilds (crash recovery, benchmark sweeps) over the
@@ -779,6 +780,15 @@ class ServingEngine:
                     tokens[i] = req.tokens_out[-1]
             want_lp = any(r is not None and r.sampling.logprobs
                           for r in self.slot_req)
+            table = self.state.get("page_table")
+            if table is not None:
+                # what the paged kernel's table walk covers, and the
+                # pages the decoding slots hold: the kernel fetches no
+                # others
+                self.stats["attn_pages_table"] += span_exec * table.size
+                self.stats["attn_pages_live"] += span_exec * sum(
+                    self.kv.held(self.slot_req[i].req_id)
+                    for i in np.nonzero(act)[0])
             out = span_program(self.cfg, self.policy, self.ecfg,
                                self.sampler, span_exec, want_lp)(
                 self.params, jnp.asarray(tokens), self.state,

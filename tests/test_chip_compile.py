@@ -47,24 +47,47 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("name", ["qwen1.5-4b", "qwen3-8b"])
-def test_paged_kernel_compiles(one_chip, name):
-    """KV=20/G=1 (MHA) and KV=8/G=4 (GQA) pools at served sizes."""
-    cfg = CONFIGS[name]
-    B, page, NP = chip_smoke.SLOTS, chip_smoke.PAGE_SIZE, 256
-    MP = chip_smoke.CACHE_LEN // page
+def _compile_kernel(one_chip, cfg, slots, n_pages, width,
+                    backend="pallas"):
     bf16 = jnp.bfloat16
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = spec((NP, cfg.n_kv_heads, page, cfg.head_dim), bf16)
+    pool = spec((n_pages, cfg.n_kv_heads, chip_smoke.PAGE_SIZE,
+                 cfg.head_dim), bf16)
     fn = jax.jit(functools.partial(paged_decode_attention,
-                                   backend="pallas"))
-    compiled = fn.lower(spec((B, cfg.n_heads, cfg.head_dim), bf16), pool,
-                        pool, spec((B, MP), jnp.int32),
-                        spec((B,), jnp.int32)).compile()
+                                   backend=backend))
+    return fn.lower(spec((slots, cfg.n_heads, cfg.head_dim), bf16), pool,
+                    pool, spec((slots, width), jnp.int32),
+                    spec((slots,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("width", [16, 32, 64, 128])
+@pytest.mark.parametrize("name,slots,n_pages", [
+    ("qwen1.5-4b", 8, 256), ("qwen3-8b", 12, 1664)])
+def test_paged_kernel_compiles(one_chip, name, slots, n_pages, width):
+    """The benchmark cells' deployments, KV=20/G=1 (MHA, chat: 8 slots,
+    256 pages) and KV=8/G=4 (GQA, reasoning: 12 slots, 1664 pages), at
+    every table width their spans export: the blocks of pages fit VMEM."""
+    compiled = _compile_kernel(one_chip, CONFIGS[name], slots, n_pages,
+                               width)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["nemotron-4-15b", "chameleon-34b",
+                                  "moonshot-v1-16b-a3b", "musicgen-large"])
+def test_paged_kernel_compiles_for_other_paged_configs(one_chip, name):
+    """The other configurations on the paged path (G=6 and 8, KV=16)
+    take their block size from the same rule; musicgen's head_dim 64 is
+    not lane-aligned, so the "auto" dispatch gives it the gather."""
+    cfg = CONFIGS[name]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = _compile_kernel(one_chip, cfg, 8, 256, 64,
+                                   backend="auto")
+    kernel = "tpu_custom_call" in compiled.as_text()
+    assert kernel == (cfg.head_dim % 128 == 0)
 
 
 @pytest.fixture(scope="module")

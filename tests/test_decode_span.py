@@ -311,6 +311,29 @@ def test_bounded_table_matches_full_width_both_backends():
                                atol=2e-2)
 
 
+def test_engine_counts_the_kernels_table_walk(tiny):
+    """attn_pages_table grows by span steps x slots x exported width,
+    attn_pages_live by span steps x pages the decoding slots hold."""
+    cfg, params = tiny
+    eng = _mk(cfg, params, 4, slots=3, cache_len=96, n_pages=64,
+              kv_layout="paged")
+    eng.submit(Request(0, _prompt(9, seed=1), max_new_tokens=4))
+    eng.submit(Request(1, _prompt(30, seed=2), max_new_tokens=4))
+    eng.run_until_done()
+    # one span: both slots owe 3 tokens, so it runs 4 steps; slot 0
+    # reserves positions to 9+3 (2 pages of 8), slot 1 to 30+3 (5
+    # pages); the table is exported at width 8 (the pow2 over 5) for
+    # 3 slots, the third free
+    assert eng.stats["decode_spans"] == 1
+    assert eng.stats["attn_pages_table"] == 4 * 3 * 8
+    assert eng.stats["attn_pages_live"] == 4 * (2 + 5)
+    eng.submit(Request(2, _prompt(17, seed=3), max_new_tokens=2))
+    eng.run_until_done()
+    # one step for one token at position 17: 3 pages, width 4
+    assert eng.stats["attn_pages_table"] == 96 + 1 * 3 * 4
+    assert eng.stats["attn_pages_live"] == 28 + 1 * 3
+
+
 def test_engine_exports_bucketed_tables(tiny):
     """PagedKV.sync exports the MTT at the live pow2 width, and the
     width tracks growth across spans."""

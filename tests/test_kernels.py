@@ -50,25 +50,108 @@ def test_flash_attention_swa(window):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,KV,hd,NP,page,MP", [
-    (2, 4, 2, 32, 16, 16, 4), (3, 8, 4, 64, 32, 8, 6), (1, 2, 1, 16, 8, 4, 3),
+def _paged(q, kp, vp, table, lengths, ppb, monkeypatch, interpret=True):
+    """The Pallas kernel in interpret mode; ``ppb`` set, the VMEM budget
+    is cut to that many pages' tile, so the shape rule derives it."""
+    if ppb is None and interpret is True:
+        return ops.paged_decode_attention(q, kp, vp, table, lengths,
+                                          interpret=True)
+    from repro.kernels import paged_attention as pa
+    _, KV, page, hd = kp.shape
+    if ppb is not None:
+        monkeypatch.setattr(pa, "BLOCK_BYTES", ppb * KV * page * hd
+                            * kp.dtype.itemsize)
+        assert pa.pages_per_block(KV, page, hd, kp.dtype.itemsize,
+                                  table.shape[1]) == ppb
+    return pa.paged_decode_attention(q, kp, vp, table, lengths,
+                                     backend="pallas", interpret=interpret)
+
+
+@pytest.mark.parametrize("B,H,KV,hd,NP,page,MP,ppb,lens", [
+    (2, 4, 2, 32, 16, 16, 4, None, None),
+    (3, 8, 4, 64, 32, 8, 6, None, None),
+    (1, 2, 1, 16, 8, 4, 3, None, None),
+    # MP not a multiple of ppb: the tail block stops at MP
+    (3, 4, 4, 32, 24, 8, 3, 2, None),
+    # G=4: one block exactly (4 pages x 4), one token, the whole table
+    (3, 16, 4, 32, 40, 4, 6, 4, (16, 1, 24)),
+    # G=1 with KV=8, a page per block
+    (2, 8, 8, 16, 16, 4, 3, 1, (12, 5)),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode(B, H, KV, hd, NP, page, MP, dtype):
+def test_paged_decode(B, H, KV, hd, NP, page, MP, ppb, lens, dtype,
+                      monkeypatch):
     ks = jax.random.split(jax.random.PRNGKey(NP + MP), 5)
     q = _rand(ks[0], (B, H, hd), dtype)
     kp = _rand(ks[1], (NP, KV, page, hd), dtype)
     vp = _rand(ks[2], (NP, KV, page, hd), dtype)
     table = jax.random.randint(ks[3], (B, MP), 0, NP)
-    lengths = jax.random.randint(ks[4], (B,), 1, MP * page + 1)
-    out = ops.paged_decode_attention(q, kp, vp, table, lengths,
-                                     interpret=True)
+    lengths = (jax.random.randint(ks[4], (B,), 1, MP * page + 1)
+               if lens is None else jnp.asarray(lens, jnp.int32))
+    out = _paged(q, kp, vp, table, lengths, ppb, monkeypatch)
     expected = ref.paged_decode_attention_ref(
         q.astype(jnp.float32), kp.astype(jnp.float32),
         vp.astype(jnp.float32), table, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expected, np.float32),
                                atol=_TOL[dtype], rtol=_TOL[dtype])
+
+
+@pytest.mark.parametrize("ppb", [None, 4, 1])
+def test_paged_decode_never_reads_dead_pages(ppb, monkeypatch):
+    """Dead table entries: id 0, stale ids past a slot's live pages, and
+    a whole slot of length 0 (outside the batch). First every page that
+    only dead entries name is NaN, as are the rows past each length in a
+    slot's last page: the output still equals the reference on the clean
+    pool, so nothing dead is mixed in (the length-0 slot reads zeros).
+    Then the dead entries name pages past the pool, and the TPU
+    interpreter raises on any read out of bounds: none is fetched."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.default_rng(7)
+    B, KV, G, hd, NP, page, MP = 5, 2, 2, 16, 40, 4, 6
+    lengths = np.array([5, 17, 24, 1, 0], np.int32)
+    n_live = -(-lengths // page)
+    ids = rng.permutation(np.arange(1, NP))
+    table = np.zeros((B, MP), np.int32)
+    live = []
+    for b, n in enumerate(n_live):
+        table[b, :n] = ids[:n]
+        live += list(ids[:n])
+        ids = ids[n:]
+    dead = ids[:8]                                 # stale ids, never live
+    table[0, 3:5] = dead[:2]
+    table[1, 5] = dead[2]
+    table[4, :3] = dead[3:6]
+    kp = rng.normal(size=(NP, KV, page, hd)).astype(np.float32)
+    vp = rng.normal(size=(NP, KV, page, hd)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(B, KV * G, hd)), jnp.float32)
+    expected = np.asarray(ref.paged_decode_attention_ref(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+        jnp.asarray(lengths)))
+
+    poisoned = np.setdiff1d(np.arange(NP), live)   # id 0 among them
+    assert 0 in poisoned and set(dead) <= set(poisoned)
+    kn, vn = kp.copy(), vp.copy()
+    kn[poisoned] = np.nan
+    vn[poisoned] = np.nan
+    for b in range(B):
+        if lengths[b] % page:
+            last = table[b, lengths[b] // page]
+            kn[last, :, lengths[b] % page:] = np.nan
+            vn[last, :, lengths[b] % page:] = np.nan
+    out = np.asarray(_paged(q, jnp.asarray(kn), jnp.asarray(vn),
+                            jnp.asarray(table), jnp.asarray(lengths), ppb,
+                            monkeypatch))
+    np.testing.assert_allclose(out[:4], expected[:4], atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(out[4], np.zeros_like(out[4]))
+
+    cols = np.arange(MP)[None, :]
+    past = np.where(cols >= n_live[:, None], NP + cols, table)
+    out = np.asarray(_paged(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(past),
+        jnp.asarray(lengths), ppb, monkeypatch,
+        interpret=pltpu.InterpretParams(out_of_bounds_reads="raise")))
+    np.testing.assert_allclose(out[:4], expected[:4], atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("T,D,E,C", [(64, 32, 8, 12), (100, 16, 4, 40),
