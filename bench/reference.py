@@ -3,10 +3,10 @@
 Straightforward ``jax.numpy`` at ``HIGHEST`` matmul precision (true
 float32 on a TPU), batch 1, no cache, no kernels and nothing of the
 program: it reads the canonical weights of ``weights.py`` and follows
-the published Qwen2/Qwen3 description (pre-RMSNorm blocks, rotary
-embedding on the two halves of each head, grouped-query causal
-attention, SwiGLU MLP; q/k/v biases and per-head q/k RMSNorm where the
-configuration has them).
+the published description of the model's family. The forward pass is
+the family's (``bench/families``, ``logits``), built from the helpers
+here (``_mm``, ``_fp8``, ``_rms``, ``_rope``), so that every family's
+reference and control follow the same float32 and float8 rules.
 
 ``gaps`` teacher-forces the reference over a prompt and the tokens the
 program served for it, and returns, for every served token, how far
@@ -33,15 +33,15 @@ positions, the gap of the token the control puts first.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import families
+
 HI = jax.lax.Precision.HIGHEST
-Q_BLOCK = 512                 # query rows per attention block
 LEN_BUCKET = 256              # sequences are padded to a multiple
 
 
@@ -75,53 +75,6 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(x, lw, *, dims, eps, theta, control):
-    S = x.shape[0]
-    H, KV, hd = dims
-    G = H // KV
-    pos = jnp.arange(S)
-    h = _rms(x, lw["norm1"], eps)
-    q, k, v = (_mm(h, lw[n], control) for n in ("wq", "wk", "wv"))
-    if "bq" in lw:
-        q = q + lw["bq"].astype(jnp.float32)
-        k = k + lw["bk"].astype(jnp.float32)
-        v = v + lw["bv"].astype(jnp.float32)
-    q, k, v = q.reshape(S, H, hd), k.reshape(S, KV, hd), v.reshape(S, KV, hd)
-    if "q_norm" in lw:
-        q = _rms(q, lw["q_norm"], eps)
-        k = _rms(k, lw["k_norm"], eps)
-    q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-    q = q.reshape(S, KV, G, hd) / math.sqrt(hd)
-    outs = []
-    for b0 in range(0, S, Q_BLOCK):
-        qb = q[b0:b0 + Q_BLOCK]
-        s = jnp.einsum("qkgd,skd->kgqs", qb, k, precision=HI)
-        causal = pos[None, :] <= (b0 + jnp.arange(qb.shape[0]))[:, None]
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        outs.append(jnp.einsum("kgqs,skd->qkgd", p, v, precision=HI))
-    a = jnp.concatenate(outs, 0).reshape(S, H * hd)
-    x = x + _mm(a, lw["wo"], control)
-    h = _rms(x, lw["norm2"], eps)
-    g, u = _mm(h, lw["w_gate"], control), _mm(h, lw["w_up"], control)
-    return x + _mm(jax.nn.silu(g) * u, lw["w_down"], control)
-
-
-@partial(jax.jit, static_argnames=("dims", "eps", "theta", "control"))
-def _logits(w, tokens, rows, *, dims, eps, theta, control):
-    """Logits [len(rows), V] of a padded sequence at the given rows."""
-    emb = w["embed"][tokens]
-    x = (_fp8(emb, -1) if control else emb.astype(jnp.float32))
-
-    def body(x, lw):
-        return _layer(x, lw, dims=dims, eps=eps, theta=theta,
-                      control=control), None
-
-    x, _ = jax.lax.scan(body, x, w["layers"])
-    x = _rms(x[rows], w["final_norm"], eps)
-    return _mm(x, w["head"], control)
-
-
 @partial(jax.jit, static_argnames=("control",))
 def _gaps(ref, lg, served, control):
     best = ref.max(-1)
@@ -138,11 +91,6 @@ def _nucleus(ref, served, temperature, top_p):
     keep = (jnp.cumsum(ps, -1) - ps) < top_p
     z = jnp.sum(jnp.where(keep, ps, 0.0), -1)
     return above - top_p, (above + pt / 2) / z
-
-
-def _dims(conf):
-    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
-    return H, KV, conf.get("head_dim") or conf["hidden_size"] // H
 
 
 def _teacher_forced(w, conf, prompt, served, pad_to, control=False):
@@ -163,10 +111,9 @@ def _teacher_forced(w, conf, prompt, served, pad_to, control=False):
     tokens[:len(seq)] = seq
     rows = np.full(n_pad, P - 1, np.int32)
     rows[:n] = P - 1 + np.arange(n)
-    kw = dict(dims=_dims(conf), eps=float(conf["rms_norm_eps"]),
-              theta=float(conf["rope_theta"]))
-    ref = _logits(w, tokens, rows, control=False, **kw)
-    lg = _logits(w, tokens, rows, control=True, **kw) if control else ref
+    fam = families.of(conf)
+    ref = fam.logits(w, conf, tokens, rows, control=False)
+    lg = fam.logits(w, conf, tokens, rows, control=True) if control else ref
     tok = np.zeros(n_pad, np.int32)
     tok[:n] = served
     return ref, lg, jnp.asarray(tok), n

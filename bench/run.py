@@ -678,7 +678,7 @@ class Session:
         self.w = self.params = None        # never two sets on the device
         gc.collect()
         self.w = wmod.make(self.conf, seed)
-        self.params = wmod.to_program(self.w)
+        self.params = wmod.to_program(self.conf, self.w)
         jax.block_until_ready(self.params)
         if self.ecfg is None:
             param_bytes = wmod.nbytes(self.w)

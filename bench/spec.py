@@ -3,8 +3,9 @@
 A cell (``workloads`` entry) names a configuration and a traffic mix.
 The configuration is ``bench/configs/<config>.json``: the model's public
 ``config.json`` numbers under their own keys, with the keys that were
-changed listed in ``reduced``. The traffic mix is
-``bench/traffic/<traffic>.json``: the arrival process, lengths and
+changed listed in ``reduced``; its ``model_type`` names the family
+(``bench/families``) that builds, checks and counts the model. The
+traffic mix is ``bench/traffic/<traffic>.json``: the arrival process, lengths and
 sampling (read by ``loadgen``), the engine deployment (``engine``) and
 the limits of the correctness check (``limits``).
 """
@@ -15,26 +16,10 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Optional
 
+from bench import families
+
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
-
-# public config.json key -> ModelConfig field of the program
-_HF_KEYS = {
-    "hidden_size": "d_model",
-    "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "torch_dtype": "dtype",
-    "attention_bias": "qkv_bias",
-    "qk_norm": "qk_norm",
-}
-
 
 @dataclass(frozen=True)
 class Cell:
@@ -66,22 +51,17 @@ def load_cell(name: str, root: Path = ROOT,
     confs = {c["name"]: c for c in bench["configs"]}
     cfile = root / confs[w["config"]]["file"]
     tfile = BENCH_DIR / "traffic" / f"{w['traffic']}.json"
+    config = json.loads(cfile.read_text())
+    families.of(config)       # refuses a model_type that no family serves
     return Cell(
         name=name, chips=int(w["chips"]),
-        config_name=w["config"], config=json.loads(cfile.read_text()),
+        config_name=w["config"], config=config,
         traffic_name=w["traffic"], traffic=json.loads(tfile.read_text()),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
 
 
 def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file (dense decoder
-    configurations: grouped-query attention, SwiGLU, RMSNorm, RoPE)."""
-    from repro.configs.base import ModelConfig
-    if conf.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"hidden_act {conf['hidden_act']!r} is not served")
-    kw = {field: conf[key] for key, field in _HF_KEYS.items() if key in conf}
-    kw.setdefault("head_dim", conf["hidden_size"]
-                  // conf["num_attention_heads"])
-    return ModelConfig(name=conf["name"], family="dense", act="swiglu",
-                       source=conf["source"], **kw)
+    """The program's ModelConfig for a configuration file, by its
+    family."""
+    return families.of(conf).model_config(conf)

@@ -3,15 +3,16 @@ hand-computed shapes."""
 import pytest
 
 from bench import flops, peaks
+from bench.families import dense
 
-TOY = {"hidden_size": 8, "num_hidden_layers": 2, "num_attention_heads": 4,
-       "num_key_value_heads": 2, "head_dim": 2, "intermediate_size": 16,
-       "vocab_size": 10}
+TOY = {"model_type": "qwen3", "hidden_size": 8, "num_hidden_layers": 2,
+       "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
+       "intermediate_size": 16, "vocab_size": 10}
 
 
 def test_layer_matmul_params():
     # per layer: wq 8*8, wk 8*4, wv 8*4, wo 8*8, 3 * 8*16
-    assert flops.layer_matmul_params(TOY) == 2 * (64 + 32 + 32 + 64 + 384)
+    assert dense.layer_matmul_params(TOY) == 2 * (64 + 32 + 32 + 64 + 384)
 
 
 def test_decode_token_flops():
@@ -36,7 +37,7 @@ def test_qwen15_4b_parameters():
             "num_attention_heads": 20, "num_key_value_heads": 20,
             "intermediate_size": 6912, "vocab_size": 151936}
     # 40 * (4 * 2560^2 + 3 * 2560 * 6912) = 3.171e9
-    assert flops.layer_matmul_params(conf) == 3_171_942_400
+    assert dense.layer_matmul_params(conf) == 3_171_942_400
 
 
 def test_roofline_takes_the_larger_bound():

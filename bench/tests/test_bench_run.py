@@ -13,8 +13,8 @@ import pytest
 
 from bench import reference, run, spec, weights
 
-TINY = {"name": "tiny", "source": "test", "hidden_size": 64,
-        "intermediate_size": 128, "num_hidden_layers": 2,
+TINY = {"name": "tiny", "source": "test", "model_type": "qwen3",
+        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
         "torch_dtype": "bfloat16", "attention_bias": True, "qk_norm": True,
@@ -151,7 +151,7 @@ def test_control_reads_above_the_program():
     conf = {**TINY, "num_hidden_layers": 4}
     cfg = spec.model_config(conf)
     w = weights.make(conf, 3)
-    params = weights.to_program(w)
+    params = weights.to_program(conf, w)
     rng = np.random.default_rng(0)
     prefill = jax.jit(lambda p, t: lm.prefill(p, t, cfg, NULL_POLICY,
                                               cache_len=72))

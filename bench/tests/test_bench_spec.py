@@ -61,6 +61,7 @@ def test_weights_fit_the_program_tree(name):
     conf = json.loads((spec.ROOT / c["file"]).read_text())
     cfg = spec.model_config(conf)
     made = jax.eval_shape(lambda: weights.to_program(
+        conf,
         {"layers": {k: jax.ShapeDtypeStruct(v[:], "bfloat16")
                     for k, v in weights.shapes(conf).items()
                     if k not in ("embed", "head", "final_norm")},
@@ -73,9 +74,10 @@ def test_weights_fit_the_program_tree(name):
 
 
 def test_weights_are_made_from_the_seed():
-    conf = {"hidden_size": 16, "num_hidden_layers": 2,
-            "num_attention_heads": 2, "num_key_value_heads": 1,
-            "head_dim": 8, "intermediate_size": 32, "vocab_size": 64,
+    conf = {"model_type": "qwen3", "hidden_size": 16,
+            "num_hidden_layers": 2, "num_attention_heads": 2,
+            "num_key_value_heads": 1, "head_dim": 8,
+            "intermediate_size": 32, "vocab_size": 64,
             "attention_bias": True, "qk_norm": True}
     a = weights.make(conf, 2**31 + 5)
     b = weights.make(conf, 2**31 + 5)

@@ -2,17 +2,11 @@
 
 The benchmark makes the weights itself, so that the reference in
 ``reference.py`` reads the same arrays the program serves from and
-nothing that the program has made. The canonical layout is per kind of
-weight, stacked over layers:
-
-    embed [V, d]   head [d, V]   final_norm [d]
-    layers: norm1, norm2 [L, d]; wq [L, d, H*hd]; wk, wv [L, d, KV*hd];
-            wo [L, H*hd, d]; bq, bk, bv (with attention_bias);
-            q_norm, k_norm [L, hd] (with qk_norm);
-            w_gate, w_up [L, d, ff]; w_down [L, ff, d]
-
-``to_program`` re-nests the same arrays (no copy) into the tree that the
-program's dense stack takes.
+nothing that the program has made. The canonical layout is the model
+family's (``bench/families``): one leaf per kind of weight, ``embed``,
+``head`` and ``final_norm`` alone and every other leaf stacked over its
+first axis (the layers) under ``"layers"``. ``to_program`` re-nests the
+same arrays (no copy) into the tree that the program takes.
 """
 from __future__ import annotations
 
@@ -23,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import families
+
 
 def device_key(seed: int):
     """A PRNG key from any whole-number seed. ``jax.random.PRNGKey`` keeps
@@ -32,21 +28,9 @@ def device_key(seed: int):
 
 
 def shapes(conf: dict) -> Dict[str, Tuple[int, ...]]:
-    """Leaf name -> shape for a configuration file (canonical layout)."""
-    d, L = conf["hidden_size"], conf["num_hidden_layers"]
-    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf.get("head_dim") or d // H
-    ff, V = conf["intermediate_size"], conf["vocab_size"]
-    s = {"embed": (V, d), "head": (d, V), "final_norm": (d,),
-         "norm1": (L, d), "norm2": (L, d),
-         "wq": (L, d, H * hd), "wk": (L, d, KV * hd), "wv": (L, d, KV * hd),
-         "wo": (L, H * hd, d),
-         "w_gate": (L, d, ff), "w_up": (L, d, ff), "w_down": (L, ff, d)}
-    if conf.get("attention_bias"):
-        s.update(bq=(L, H * hd), bk=(L, KV * hd), bv=(L, KV * hd))
-    if conf.get("qk_norm"):
-        s.update(q_norm=(L, hd), k_norm=(L, hd))
-    return s
+    """Leaf name -> shape for a configuration file (its family's canonical
+    layout)."""
+    return families.of(conf).shapes(conf)
 
 
 _TOP = ("embed", "head", "final_norm")
@@ -90,17 +74,10 @@ def make(conf: dict, seed: int):
     return jax.jit(build)(device_key(seed))
 
 
-def to_program(w: dict) -> dict:
-    """The same arrays, nested as the program's dense stack takes them."""
-    lw = w["layers"]
-    attn = {k: lw[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv",
-                               "q_norm", "k_norm") if k in lw}
-    mlp = {k: lw[k] for k in ("w_gate", "w_up", "w_down")}
-    block = {"norm1": lw["norm1"], "norm2": lw["norm2"], "attn": attn,
-             "mlp": mlp}
-    return {"embed": w["embed"], "head": w["head"],
-            "final_norm": w["final_norm"],
-            "stack": {"prefix": [], "groups": {"b0": block}}}
+def to_program(conf: dict, w: dict) -> dict:
+    """The same arrays, nested as the program takes them (by the
+    configuration's family)."""
+    return families.of(conf).to_program(w)
 
 
 def nbytes(tree) -> int:
